@@ -15,6 +15,7 @@ import sys
 import time
 
 from repro import exp
+from repro.runtime.compile_cache import use_compile_cache
 
 
 def main(argv=None) -> None:
@@ -26,6 +27,7 @@ def main(argv=None) -> None:
     ap.add_argument("--only", default=None, metavar="NAME",
                     help="run a single benchmark module (e.g. fig8_perf)")
     args = ap.parse_args(argv)
+    use_compile_cache()
     engine = exp.EngineConfig.from_args(args)
 
     mods = (table1, fig7_breakdown, fig9_expdiff, fig8_perf,

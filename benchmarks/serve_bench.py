@@ -617,21 +617,21 @@ def _bench_failover():
 
 
 def run(verbose: bool = True, repeats: int = 3):
-    """Whole-bench wrapper: fused executors default to the Pallas
-    backend, which on CPU means interpret mode — pure tracing overhead
-    that would drown the datapath being measured. Pin the identical-math
+    """Whole-bench wrapper: on the CPU the fused executors' Pallas
+    backend runs in interpret mode — pure tracing overhead that would
+    drown the datapath being measured. There, pin the identical-math
     XLA reference backend for the duration of the bench (unless the
     caller pinned one explicitly) so every wall-clock row, fused or
-    staged, measures real compute."""
-    prev = os.environ.get("REPRO_FUSED_BACKEND")
-    os.environ["REPRO_FUSED_BACKEND"] = prev or "xla"
+    staged, measures real compute. On an accelerator the kernels
+    compile, and the bench runs them."""
+    if (jax.default_backend() != "cpu"
+            or os.environ.get("REPRO_FUSED_BACKEND")):
+        return _run(verbose, repeats)
+    os.environ["REPRO_FUSED_BACKEND"] = "xla"
     try:
         return _run(verbose, repeats)
     finally:
-        if prev is None:
-            os.environ.pop("REPRO_FUSED_BACKEND", None)
-        else:
-            os.environ["REPRO_FUSED_BACKEND"] = prev
+        os.environ.pop("REPRO_FUSED_BACKEND", None)
 
 
 def _run(verbose: bool = True, repeats: int = 3):

@@ -27,6 +27,7 @@ import numpy as np
 import jax
 
 from repro.configs import reduced
+from repro.runtime.compile_cache import use_compile_cache
 from repro.serving import (EngineConfig, Request, Router, SamplingParams,
                            ServingEngine, build_replicas)
 
@@ -80,7 +81,7 @@ def run_router(args, cfg):
     total_new = sum(r.new_tokens for r in completed.values())
     print(f"\nstrategy={router.strategy} requests={args.requests} "
           f"completed={len(completed)} ticks={ticks} "
-          f"({total_new / dt:.1f} tok/s on CPU)")
+          f"({total_new / dt:.1f} tok/s on {jax.default_backend()})")
     for name, rep in router.report()["replicas"].items():
         m = rep["metrics"]
         print(f"  {name}: routed={rep['routed']} "
@@ -118,7 +119,7 @@ def run_single(args, cfg):
           f"decode_block={engine.decode_block}"
           + (" calibrated" if m["act_calibrated"] else ""))
     print(f"generated {total_new} tokens in {dt:.2f}s "
-          f"({total_new / dt:.1f} tok/s on CPU); "
+          f"({total_new / dt:.1f} tok/s on {jax.default_backend()}); "
           f"ttft_p50={_pct(m['ttft_s'])} "
           f"queue_p90={_pct(m['queue_delay_s'], 'p90')} "
           f"prefill_calls={m['counters']['prefill_calls']} "
@@ -169,6 +170,7 @@ def main():
                          "(SamplingParams; 0 = greedy, seeded on-device "
                          "sampling otherwise)")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = reduced("qwen2-0.5b")
     if args.replicas:

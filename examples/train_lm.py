@@ -16,6 +16,7 @@ import numpy as np
 
 from repro.configs import reduced
 from repro.data.pipeline import DataConfig, SyntheticLMDataset
+from repro.launch.mesh import make_mesh
 from repro.launch.train import TrainConfig, init_state, make_train_step
 from repro.models import registry
 from repro.optim import AdamWConfig
@@ -51,10 +52,10 @@ def main():
     print(f"arch={cfg.arch_id} params~{cfg.params_count()/1e6:.1f}M "
           f"policy={args.policy}")
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     tc = TrainConfig(adamw=AdamWConfig(lr=args.lr), warmup=20,
                      total_steps=args.steps)
-    with mesh:
+    with jax.set_mesh(mesh):
         step_fn, st_shard, _ = make_train_step(api, mesh, tc)
         state = init_state(api, jax.random.PRNGKey(0))
 

@@ -11,15 +11,20 @@ Simulator sweeps are embarrassingly parallel numpy/jax-CPU work; the
 pool uses the ``spawn`` start method (the parent has JAX's internal
 threads running, so forking risks deadlock) and spawn propagates
 ``sys.path``, so ``"benchmarks.fig8_perf:eval_point"`` style references
-resolve in children exactly as in the parent.
+resolve in children exactly as in the parent. The children evaluate the
+paper's simulator, never the serving hot path, so they are pinned to
+JAX's CPU backend before they start: on a TPU host a child that
+reached for the chip would fail or hang while the parent holds it.
 """
 from __future__ import annotations
 
 import argparse
 import concurrent.futures
+import contextlib
 import dataclasses
 import importlib
 import multiprocessing
+import os
 import sys
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -143,8 +148,9 @@ def run_sweep(spec: SweepSpec,
     else:
         workers = min(engine.jobs, len(todo))
         ctx = multiprocessing.get_context("spawn")
-        with concurrent.futures.ProcessPoolExecutor(workers,
-                                                    mp_context=ctx) as pool:
+        with _cpu_only_children(), \
+                concurrent.futures.ProcessPoolExecutor(
+                    workers, mp_context=ctx) as pool:
             futs = {pool.submit(_eval_point, points[i]): i for i in todo}
             n_done = 0
             first_exc: Optional[Exception] = None
@@ -169,6 +175,24 @@ def run_sweep(spec: SweepSpec,
         print(f"[exp:{spec.name}] {report.summary()}", file=sys.stderr,
               flush=True)
     return list(zip(points, results)), report
+
+
+@contextlib.contextmanager
+def _cpu_only_children():
+    """Spawned children inherit the environment at start-up, so JAX in
+    a sweep worker reads ``JAX_PLATFORMS=cpu`` before it picks a
+    backend. JAX reads the variable once, at import, so importing it
+    here first keeps the parent's own choice."""
+    import jax  # noqa: F401
+    prev = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    try:
+        yield
+    finally:
+        if prev is None:
+            del os.environ["JAX_PLATFORMS"]
+        else:
+            os.environ["JAX_PLATFORMS"] = prev
 
 
 def _progress(engine: EngineConfig, name: str, done: int, total: int) -> None:

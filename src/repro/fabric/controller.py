@@ -932,7 +932,14 @@ def spawn_subprocess_worker(controller: Controller,
     ``--resume`` so a dropped connection redials the listener —
     pass the controller's persistent ``listen()`` socket in that case
     (an ephemeral one closes after the first accept and the redial
-    would find nobody home)."""
+    would find nobody home).
+
+    Each worker process runs its own engine and takes the accelerator
+    JAX finds. A TPU chip belongs to one process at a time, so on a
+    chip machine the controller process must stay off JAX and every
+    worker needs a host (or a chip) of its own: spawning several
+    workers next to each other on one chip host makes all but the first
+    fail or hang at start-up."""
     import subprocess
     import sys
 

@@ -17,7 +17,7 @@ Two kernels:
 
 * :func:`fused_qmm` — the exact-INT datapath (per-channel scales,
   static act scale): in-register activation quantize, int32 MXU
-  accumulation across k blocks, epilogue ``acc * sa * sw`` — BIT-EXACT
+  accumulation across k blocks, epilogue ``acc * (sa * sw)`` — BIT-EXACT
   to ``quantize_symmetric(scale=sa)`` + ``qmm.qmm[_packed]`` +
   ``ops._scale_epilogue`` (same elementwise ops in the same order).
 * :func:`fused_dequant_mm` — the general f32 datapath (any storage
@@ -26,10 +26,15 @@ Two kernels:
   the block, scales broadcast over their K-groups, f32 accumulation.
 
 Blocking mirrors qmm.py: grid (M/bm, N/bn, K/bk) with k innermost and
-sequential; accumulators live in revisited output blocks. Per-group
-scales constrain bk to a multiple of the group size (the wrappers pick
-``bk = g * max(1, 256 // g)``) so every k block covers whole groups and
-the scale block is ``(bk // g, bn)``.
+sequential. The f32 accumulator of :func:`fused_dequant_mm` is its
+revisited output block; the int32 accumulator of :func:`fused_qmm`
+lives in VMEM scratch. Int operands reach the MXU as int8 (activations
+quantize to int8, int4 nibbles unpack to int8) with int32 accumulation.
+Per-group scales constrain bk to a multiple of the group size (the
+wrappers pick ``bk = g * max(1, 256 // g)``) so every k block covers
+whole groups; the (G, N) scales are viewed as (K/bk, bk // g, N) so a
+k block's scale tile is the whole trailing (bk // g, bn) slab, which
+the TPU tiling rule admits for any group count.
 """
 from __future__ import annotations
 
@@ -38,8 +43,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.qmm import _pad_to
+from repro.kernels.qmm import _int8_dot, _pad_to, _unpack_int4_block
 from repro.quant.quantize import FP4_E2M1, FP8_E4M3, fp_decode
 
 # storage kinds the kernels decode in-register
@@ -53,7 +59,7 @@ def _decode_block(w, kind: str) -> jax.Array:
     if kind in ("int8", "int4"):
         return w.astype(jnp.float32)
     if kind == "int4_packed":
-        return _int_block(w, kind).astype(jnp.float32)
+        return _unpack_int4_block(w).astype(jnp.float32)
     if kind in ("fp8", "fp4"):
         return fp_decode(w, FP8_E4M3 if kind == "fp8" else FP4_E2M1)
     if kind == "fp4_packed":
@@ -66,17 +72,6 @@ def _decode_block(w, kind: str) -> jax.Array:
     raise ValueError(f"unknown storage kind {kind!r}")
 
 
-def _int_block(w, kind: str) -> jax.Array:
-    """Stored int block -> int32 values (exact datapath)."""
-    if kind == "int4_packed":
-        p = w.astype(jnp.int32)
-        lo = ((p & 0xF) ^ 8) - 8
-        hi = p >> 4
-        k2, n = p.shape
-        return jnp.stack([lo, hi], axis=1).reshape(2 * k2, n)
-    return w.astype(jnp.int32)
-
-
 def _quantize_act(x, sa):
     """In-register mirror of ``quantize_symmetric(x, 8, scale=sa)``."""
     return jnp.clip(jnp.round(x / sa), -128.0, 127.0)
@@ -84,25 +79,24 @@ def _quantize_act(x, sa):
 
 def _fused_qmm_kernel(x_ref, w_ref, sw_ref, sa_ref, o_ref, acc_ref, *,
                       kind: str):
-    """Exact INT: quantize acts in-register, int32 accumulate, fused
-    ``acc * sa * sw`` epilogue at the last k step."""
+    """Exact INT: quantize acts in-register to int8, int8 x int8 MXU
+    dot into the int32 VMEM accumulator, fused ``acc * (sa * sw)``
+    epilogue at the last k step."""
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        o_ref[...] = jnp.zeros_like(o_ref)
 
     sa = sa_ref[0, 0]
     aq = _quantize_act(x_ref[...].astype(jnp.float32), sa)
-    b = _int_block(w_ref[...], kind)
-    acc_ref[...] += jax.lax.dot_general(
-        aq.astype(jnp.int32), b, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
+    w = w_ref[...]
+    b = _unpack_int4_block(w) if kind == "int4_packed" else w
+    acc_ref[...] += _int8_dot(aq.astype(jnp.int8), b)
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _epilogue():
         # identical op order to ops._scale_epilogue with a 0-d scale_a
-        o_ref[...] = (acc_ref[...].astype(jnp.float32) * sa
-                      * sw_ref[...].astype(jnp.float32))
+        o_ref[...] = acc_ref[...].astype(jnp.float32) * (
+            sa * sw_ref[...].astype(jnp.float32))
 
 
 def _fused_dequant_kernel(x_ref, w_ref, sw_ref, sa_ref, o_ref, *,
@@ -179,7 +173,7 @@ def fused_qmm(x: jax.Array, w: jax.Array, sw: jax.Array, sa: jax.Array,
     mp, kp = x.shape
     np_ = w.shape[1]
     wb = bk // 2 if packed else bk
-    out, _ = pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_fused_qmm_kernel, kind=kind),
         grid=(mp // bm, np_ // bn, kp // bk),
         in_specs=[
@@ -188,14 +182,9 @@ def fused_qmm(x: jax.Array, w: jax.Array, sw: jax.Array, sa: jax.Array,
             pl.BlockSpec((1, bn), lambda mi, ni, ki: (0, ni)),
             pl.BlockSpec((1, 1), lambda mi, ni, ki: (0, 0)),
         ],
-        out_specs=(
-            pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
-            pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((mp, np_), jnp.float32),
-            jax.ShapeDtypeStruct((mp, np_), jnp.int32),  # accumulator
-        ),
+        out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
+        out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
     )(x, w, sw, sa2)
     return out[:m, :n]
@@ -229,10 +218,8 @@ def fused_dequant_mm(x: jax.Array, w: jax.Array, sw: jax.Array,
     if groups > 1:
         bk = _group_bk(k, sw, bk)
         groups_per_block = bk // (k // groups)
-        sw_index = lambda mi, ni, ki: (ki, ni)       # noqa: E731
     else:
         groups_per_block = 1                         # per-channel
-        sw_index = lambda mi, ni, ki: (0, ni)        # noqa: E731
     assert bk % 2 == 0
     packed = kind in PACKED_KINDS
     x = _pad_to(x.astype(jnp.float32), (bm, bk))
@@ -240,6 +227,11 @@ def fused_dequant_mm(x: jax.Array, w: jax.Array, sw: jax.Array,
     # padded K rows decode to zero-valued weights, so padded (zero)
     # scale groups are harmless
     sw = _pad_to(sw, (groups_per_block, bn))
+    # one (groups_per_block, N) slab per k block: the block's trailing
+    # dims then equal the array's, which the TPU tiling rule accepts
+    # for any group count (a (groups_per_block, bn) tile of a (G, N)
+    # array would need groups_per_block % 8 == 0)
+    sw = sw.reshape(-1, groups_per_block, sw.shape[-1])
     sa2 = (jnp.zeros((1, 1), jnp.float32) if sa is None
            else jnp.asarray(sa, jnp.float32).reshape(1, 1))
     mp, kp = x.shape
@@ -252,7 +244,9 @@ def fused_dequant_mm(x: jax.Array, w: jax.Array, sw: jax.Array,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda mi, ni, ki: (mi, ki)),
             pl.BlockSpec((wb, bn), lambda mi, ni, ki: (ki, ni)),
-            pl.BlockSpec((groups_per_block, bn), sw_index),
+            pl.BlockSpec((None, groups_per_block, bn),
+                         lambda mi, ni, ki: (ki if groups > 1 else 0, 0,
+                                             ni)),
             pl.BlockSpec((1, 1), lambda mi, ni, ki: (0, 0)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),

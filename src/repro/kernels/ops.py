@@ -9,15 +9,12 @@
     interpreter overhead that would pollute §Perf numbers) and as the
     oracle in kernel tests.
 
-Interpret mode is resolved per call from the ``REPRO_KERNEL_INTERPRET``
-environment variable (1/0, true/false; default: interpret everywhere
-except on a real TPU backend) and passed down as a jit *static*
-argument — no module global to mutate, so launch scripts configure it
-through the environment and concurrent callers can't race on it. The
-wrappers' own jit caches key on the resolved choice; a caller that
-traces these wrappers inside an *outer* jit (e.g. the serving engine's
-decode program) bakes the choice in at trace time, so set the
-environment before building such programs.
+Interpret mode follows the platform alone: the kernels compile to
+Mosaic when JAX's default backend is a TPU and run in the Pallas
+interpreter everywhere else. The choice reaches the kernels as a jit
+*static* argument, so the wrappers' jit caches key on it and an outer
+jit (e.g. the serving engine's decode program) bakes it in at trace
+time.
 
 Quantized matmul wrappers fold per-channel scales in an epilogue, which
 is how the deployment path (quant/ + layers/mplinear.py) consumes them:
@@ -28,7 +25,6 @@ ahead-of-time nibble-packed weights (quant.prepare) through
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -40,23 +36,9 @@ from repro.kernels import mpmm as _mpmm
 from repro.kernels import qmm as _qmm
 from repro.kernels import ref as _ref
 
-_TRUE = ("1", "true", "yes", "on")
-_FALSE = ("0", "false", "no", "off")
-
-
 def kernel_interpret() -> bool:
-    """Interpret-mode choice for the Pallas kernels, read per call.
-
-    ``REPRO_KERNEL_INTERPRET`` overrides (1/0, true/false); the default
-    interprets everywhere except on a real TPU backend. Read at wrapper
-    level so it reaches the kernels as a static jit argument (resolved
-    at trace time when called from inside an outer jit).
-    """
-    v = os.environ.get("REPRO_KERNEL_INTERPRET", "").strip().lower()
-    if v in _TRUE:
-        return True
-    if v in _FALSE:
-        return False
+    """Interpret the Pallas kernels unless the default backend is a
+    TPU, where they compile."""
     return jax.default_backend() != "tpu"
 
 
@@ -118,12 +100,16 @@ def _scale_epilogue(acc: jax.Array, scale_a: jax.Array,
 
     ``scale_a`` is either per-row (M,) — dynamic per-token absmax — or a
     0-d scalar: a *calibrated static* activation scale (quant.calibrate)
-    rides straight in with no broadcast and no per-row gather."""
+    rides straight in with no broadcast and no per-row gather.
+
+    The two scales multiply first, then the accumulator: XLA's algebraic
+    simplifier reassociates ``acc * sa * sw`` into this order anyway, so
+    writing it out keeps the fused kernels' epilogue bit-identical."""
     scale_a = jnp.asarray(scale_a, jnp.float32)
     if scale_a.ndim:
         scale_a = scale_a[:, None]
-    return (acc.astype(jnp.float32) * scale_a
-            * scale_b[None, :].astype(jnp.float32))
+    return acc.astype(jnp.float32) * (
+        scale_a * scale_b[None, :].astype(jnp.float32))
 
 
 def quantized_matmul(a_q: jax.Array, b_q: jax.Array, scale_a: jax.Array,

@@ -22,38 +22,42 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+def _int8_dot(a, b):
+    """int8 x int8 -> int32 on the MXU (operands stay int8)."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+
+
+def _unpack_int4_block(packed) -> jax.Array:
+    """(bk//2, bn) packed bytes -> (bk, bn) int8 values. Nibbles are
+    sign-extended in int32 registers, interleaved back along K, and
+    narrowed to int8 for the MXU."""
+    p = packed.astype(jnp.int32)
+    lo = ((p & 0xF) ^ 8) - 8                 # sign-extend low nibble
+    hi = p >> 4                              # arithmetic: sign-extended
+    k2, n = p.shape
+    return jnp.stack([lo, hi], axis=1).reshape(2 * k2, n).astype(jnp.int8)
+
+
 def _qmm_kernel(a_ref, b_ref, o_ref):
     """o[m,n] += sum_k a[m,k] * b[k,n] in int32."""
     @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    a = a_ref[...].astype(jnp.int32)
-    b = b_ref[...].astype(jnp.int32)
-    o_ref[...] += jax.lax.dot_general(
-        a, b, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
+    o_ref[...] += _int8_dot(a_ref[...], b_ref[...])
 
 
 def _qmm_packed_kernel(a_ref, bp_ref, o_ref):
     """Packed-INT4 weights: bp holds two nibbles per byte along K.
 
-    bp[k2, n] byte = (w[2*k2+1] << 4) | (w[2*k2] & 0xF); nibbles are
-    sign-extended in-register, interleaved back to (bk, bn).
+    bp[k2, n] byte = (w[2*k2+1] << 4) | (w[2*k2] & 0xF).
     """
     @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    a = a_ref[...].astype(jnp.int32)          # (bm, bk)
-    packed = bp_ref[...].astype(jnp.int32)    # (bk//2, bn)
-    lo = ((packed & 0xF) ^ 8) - 8             # sign-extend low nibble
-    hi = packed >> 4                          # arithmetic: sign-extended
-    bk2, bn = packed.shape
-    b = jnp.stack([lo, hi], axis=1).reshape(2 * bk2, bn)  # (bk, bn)
-    o_ref[...] += jax.lax.dot_general(
-        a, b, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
+    o_ref[...] += _int8_dot(a_ref[...], _unpack_int4_block(bp_ref[...]))
 
 
 def _pad_to(x: jax.Array, mults) -> jax.Array:

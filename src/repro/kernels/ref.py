@@ -68,8 +68,8 @@ def fused_qmm_ref(x: jax.Array, w: jax.Array, sw: jax.Array,
     aq = jnp.clip(jnp.round(x.astype(jnp.float32) / sa), -128, 127)
     wq = unpack_int4_ref(w) if kind == "int4_packed" else w
     acc = qmm_ref(aq.astype(jnp.int8), wq)
-    return (acc.astype(jnp.float32) * sa
-            * sw.reshape(-1)[None, :].astype(jnp.float32))
+    return acc.astype(jnp.float32) * (
+        sa * sw.reshape(-1)[None, :].astype(jnp.float32))
 
 
 def fused_dequant_mm_ref(x: jax.Array, w: jax.Array, sw: jax.Array,

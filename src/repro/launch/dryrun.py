@@ -92,7 +92,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     batch_shape = registry.input_specs(cfg, shape)
     t0 = time.time()
 
-    with mesh:
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             tc = TrainConfig(compression=compression,
                              microbatches=microbatches)

@@ -25,12 +25,14 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import InputShape, ModelConfig
+from repro.launch.mesh import make_mesh
 from repro.models import registry
 from repro.optim import (AdamWConfig, adamw_init, adamw_update,
                          warmup_cosine)
 from repro.optim.loss_scale import (LossScaleState, grads_finite,
                                     loss_scale_init, loss_scale_update)
 from repro.parallel import sharding as shd
+from repro.runtime.compile_cache import use_compile_cache
 
 
 class TrainState(NamedTuple):
@@ -57,6 +59,15 @@ def init_state(api: registry.ModelAPI, key) -> TrainState:
     params = api.init(key)
     return TrainState(params, adamw_init(params), loss_scale_init(),
                       jnp.zeros((), jnp.int32))
+
+
+def init_sharded_state(api: registry.ModelAPI, key,
+                       shardings: TrainState) -> TrainState:
+    """``init_state`` built straight into its shardings: no device ever
+    holds the whole state (the values match ``init_state``'s, as JAX's
+    random bits do not depend on the partitioning)."""
+    return jax.jit(functools.partial(init_state, api),
+                   out_shardings=shardings)(key)
 
 
 def state_shardings(state_shape: TrainState, mesh: Mesh) -> TrainState:
@@ -183,6 +194,7 @@ def main():
     ap.add_argument("--policy", default=None)
     ap.add_argument("--lr", type=float, default=3e-4)
     args = ap.parse_args()
+    use_compile_cache()
 
     from repro.configs import get_config, reduced
     from repro.data.pipeline import DataConfig, SyntheticLMDataset
@@ -192,26 +204,25 @@ def main():
     if args.policy:
         cfg = dataclasses.replace(cfg, precision_policy=args.policy)
     api = registry.build(cfg)
-    mesh = jax.make_mesh((1, jax.device_count()), ("data", "model")) \
-        if jax.device_count() > 1 else \
-        jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, jax.device_count()), ("data", "model"))
     tc = TrainConfig(adamw=AdamWConfig(lr=args.lr),
                      total_steps=args.steps)
-    step_fn, st_shard, _ = make_train_step(api, mesh, tc)
-    state = init_state(api, jax.random.PRNGKey(0))
+    with jax.set_mesh(mesh):
+        step_fn, st_shard, _ = make_train_step(api, mesh, tc)
+        state = init_sharded_state(api, jax.random.PRNGKey(0), st_shard)
 
-    ds = SyntheticLMDataset(DataConfig(
-        vocab=cfg.vocab, seq_len=args.seq, global_batch=args.batch))
+        ds = SyntheticLMDataset(DataConfig(
+            vocab=cfg.vocab, seq_len=args.seq, global_batch=args.batch))
 
-    loop = FaultTolerantLoop(
-        step_fn=lambda s, b: step_fn(s, b),
-        batch_fn=ds.batch,
-        ckpt_dir=args.ckpt_dir,
-        cfg=FTConfig(checkpoint_every=args.ckpt_every),
-    )
-    t0 = time.time()
-    state, step = loop.run(state, 0, args.steps)
-    dt = time.time() - t0
+        loop = FaultTolerantLoop(
+            step_fn=lambda s, b: step_fn(s, b),
+            batch_fn=ds.batch,
+            ckpt_dir=args.ckpt_dir,
+            cfg=FTConfig(checkpoint_every=args.ckpt_every),
+        )
+        t0 = time.time()
+        state, step = loop.run(state, 0, args.steps)
+        dt = time.time() - t0
     losses = [h["loss"] for h in loop.history]
     print(f"arch={cfg.arch_id} steps={step} time={dt:.1f}s "
           f"loss[0]={losses[0]:.4f} loss[-1]={losses[-1]:.4f} "

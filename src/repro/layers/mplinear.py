@@ -195,6 +195,20 @@ def _note_act_absmax(path: Optional[str], x: jax.Array):
 
 # ------------------------------------------------------------ executors
 
+def _rounded(v: jax.Array, dtype) -> jax.Array:
+    """``v`` in f32, rounded to ``dtype``'s precision by an explicit op.
+
+    XLA may skip a bf16 rounding inside a fusion (excess precision), and
+    whether it does depends on how the surrounding program fused. The
+    exact int paths round their input and output explicitly, so the
+    staged and fused programs quantize the same activations and hand on
+    the same values, whatever XLA fused around them."""
+    fi = jnp.finfo(dtype)
+    return jax.lax.reduce_precision(v.astype(jnp.float32),
+                                    exponent_bits=fi.nexp,
+                                    mantissa_bits=fi.nmant)
+
+
 def _weight_scale_vec(w: PreparedWeight) -> jax.Array:
     """(N,) per-out-channel scales from the stored keepdims layout."""
     return w.scale.reshape(-1)
@@ -243,7 +257,7 @@ def _int_executor(w, x, spec: PrecisionSpec, compute_dtype):
                          "exact integer kernels need int storage "
                          "(stage_params never stages exact specs)")
     lead = x.shape[:-1]
-    x2 = x.reshape(-1, x.shape[-1])
+    x2 = _rounded(x, x.dtype).reshape(-1, x.shape[-1])
     if act_scale is None:
         note_act_quant()
         aq, sa = quantize_symmetric(x2, 8, axis=1)
@@ -324,7 +338,7 @@ def _int_fused_executor(w, x, spec: PrecisionSpec, compute_dtype):
     if not fusable:
         return _int_executor(w, x, spec, compute_dtype)
     lead = x.shape[:-1]
-    x2 = x.astype(jnp.float32).reshape(-1, x.shape[-1])
+    x2 = _rounded(x, x.dtype).reshape(-1, x.shape[-1])
     sa = w.act_scale
     backend = _fused_backend()
     if spec.exact and w.scale_groups == 1:
@@ -402,4 +416,6 @@ def mp_linear(params, x: jax.Array, spec: PrecisionSpec,
     b = params.get("b")
     if b is not None:
         y = y + b.astype(y.dtype)
+    if spec.exact:
+        y = _rounded(y, compute_dtype)
     return y.astype(compute_dtype)

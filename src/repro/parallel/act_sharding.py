@@ -11,7 +11,10 @@ carries the ZeRO 'data' shard, or padding 14 attention heads onto a
     logits:             batch over dp, vocab over 'model'
 
 They are no-ops outside a mesh context (single-device smoke tests) and
-silently drop axes that do not divide the dimension.
+silently drop axes that do not divide the dimension. The context is the
+mesh entered with ``jax.set_mesh`` (``launch.mesh`` builds Auto axes);
+only Auto axes are pinned — Manual axes inside a ``shard_map`` body and
+Explicit axes already carry their sharding in the types.
 """
 from __future__ import annotations
 
@@ -19,39 +22,23 @@ from typing import Optional, Sequence, Tuple, Union
 
 import jax
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 DP = "__dp__"        # sentinel: the data-parallel axes ('pod','data')
 MDL = "__model__"    # sentinel: the tensor-parallel axis
 
 
 def _ambient_mesh():
-    # Inside shard_map bodies the abstract mesh carries axis types (pod is
-    # Manual there — constraints must not name it); otherwise fall back to
-    # the `with mesh:` context mesh.
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is not None and am.axis_names:
-            return am
-    except Exception:
-        pass
-    try:
-        import jax._src.mesh as mesh_lib
-        m = mesh_lib.thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:  # pragma: no cover - jax internals moved
-        return None
+    """The mesh set by ``jax.set_mesh``, with its axis types (inside a
+    shard_map body the mapped axes are Manual); None outside one."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def _usable(mesh, name) -> bool:
-    if name not in mesh.axis_names:
-        return False
-    try:
-        from jax.sharding import AxisType
-        t = dict(zip(mesh.axis_names, mesh.axis_types))[name]
-        return t != AxisType.Manual
-    except Exception:
-        return True
+    return (name in mesh.axis_names
+            and mesh.axis_types[mesh.axis_names.index(name)]
+            == AxisType.Auto)
 
 
 def _resolve(axis, mesh):

@@ -21,6 +21,7 @@ import numpy as np  # noqa: E402
 from repro.configs import reduced  # noqa: E402
 from repro.configs.base import InputShape  # noqa: E402
 from repro.data.pipeline import batch_for  # noqa: E402
+from repro.launch.mesh import make_mesh as auto_mesh  # noqa: E402
 from repro.launch.train import (TrainConfig, init_state,  # noqa: E402
                                 make_train_step, state_shardings)
 from repro.models import registry  # noqa: E402
@@ -29,10 +30,10 @@ from repro.parallel import sharding as shd  # noqa: E402
 
 def make_mesh(name):
     if name == "multi":
-        return jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        return auto_mesh((2, 2, 2), ("pod", "data", "model"))
     if name == "mesh42":
-        return jax.make_mesh((4, 2), ("data", "model"))
-    return jax.make_mesh((2, 4), ("data", "model"))
+        return auto_mesh((4, 2), ("data", "model"))
+    return auto_mesh((2, 4), ("data", "model"))
 
 
 def main():
@@ -45,9 +46,9 @@ def main():
     mesh = make_mesh(mesh_name)
 
     if mode in ("lower", "run"):
-        with mesh:
-            step, st_sh, _ = make_train_step(api, mesh, TrainConfig(),
-                                             batch_shape)
+        with jax.set_mesh(mesh):
+            step, st_sh, b_sh = make_train_step(api, mesh, TrainConfig(),
+                                                batch_shape)
             if mode == "lower":
                 state_shape = jax.eval_shape(
                     lambda k: init_state(api, k), jax.random.PRNGKey(0))
@@ -58,7 +59,7 @@ def main():
             state = jax.device_put(state, st_sh)
             losses = []
             for i in range(3):
-                batch = batch_for(cfg, shape, i)
+                batch = jax.device_put(batch_for(cfg, shape, i), b_sh)
                 state, metrics = step(state, batch)
                 losses.append(float(metrics["loss"]))
             assert all(np.isfinite(l) for l in losses), losses
@@ -71,32 +72,33 @@ def main():
         from repro.launch.train import TrainState
         tmp = tempfile.mkdtemp()
         mesh_a = make_mesh("single")
-        with mesh_a:
-            step_a, sh_a, _ = make_train_step(api, mesh_a, TrainConfig(),
-                                              batch_shape)
+        with jax.set_mesh(mesh_a):
+            step_a, sh_a, bsh_a = make_train_step(
+                api, mesh_a, TrainConfig(), batch_shape)
             state = jax.device_put(init_state(api, jax.random.PRNGKey(0)),
                                    sh_a)
-            batch = batch_for(cfg, shape, 0)
+            batch = jax.device_put(batch_for(cfg, shape, 0), bsh_a)
             state, m0 = step_a(state, batch)
             CheckpointManager(tmp).save(1, state)
         # restore onto a different mesh topology
         mesh_b = make_mesh("mesh42")
-        with mesh_b:
-            step_b, sh_b, _ = make_train_step(api, mesh_b, TrainConfig(),
-                                              batch_shape)
+        with jax.set_mesh(mesh_b):
+            step_b, sh_b, bsh_b = make_train_step(
+                api, mesh_b, TrainConfig(), batch_shape)
             state_shape = jax.eval_shape(
                 lambda k: init_state(api, k), jax.random.PRNGKey(0))
             s, st, _ = CheckpointManager(tmp).restore_latest(state_shape,
                                                              sh_b)
             assert s == 1
-            st2, m1 = step_b(st, batch_for(cfg, shape, 1))
+            st2, m1 = step_b(st, jax.device_put(batch_for(cfg, shape, 1),
+                                                bsh_b))
             assert np.isfinite(float(m1["loss"]))
             print("ELASTIC_OK", f"{float(m1['loss']):.4f}")
             return
 
     if mode == "serve":
         cache_len = 64
-        with mesh:
+        with jax.set_mesh(mesh):
             param_shape = jax.eval_shape(api.init, jax.random.PRNGKey(0))
             p_sh = shd.param_shardings(param_shape, mesh)
             cache_shape = jax.eval_shape(lambda: api.init_cache(8, cache_len))

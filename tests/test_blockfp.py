@@ -8,8 +8,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-pytest.importorskip("hypothesis", reason="install the [dev] extra")
-from hypothesis import given, settings, strategies as st  # noqa: E402
+from hypothesis import given, settings, strategies as st
 
 from repro.parallel.blockfp import blockfp_dequantize, blockfp_quantize
 
@@ -19,9 +18,10 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys; sys.path.insert(0, "src")
 import jax, jax.numpy as jnp, numpy as np, re
 from repro.parallel.blockfp import make_pod_exchange
+from repro.launch.mesh import make_mesh
 from repro.launch.roofline import parse_collectives
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 rng = np.random.default_rng(0)
 grads = {"wq": {"w": jnp.asarray(rng.normal(0, 1e-3, (2, 64, 64)),
                                  jnp.float32)},
@@ -33,7 +33,7 @@ ref = jax.tree.map(lambda g: jnp.broadcast_to(g.mean(0), g.shape), grads)
 wire = {}
 for method in ("f32", "int8", "blockfp8"):
     fn, in_sh, out_sh = make_pod_exchange(mesh, shapes, method)
-    with mesh:
+    with jax.set_mesh(mesh):
         out = fn(jax.device_put(grads, in_sh))
         txt = fn.lower(shapes).compile().as_text()
     err = max(float(jnp.abs(a - b).max() / jnp.abs(b).max())

@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import exact_ref, error_bounds, fixedpoint as fx, fp16 as fpmod
 from repro.core import ehu, nibble

@@ -3,7 +3,7 @@ configs, and the HLO collective parser."""
 import numpy as np
 import jax.numpy as jnp
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.policy import POLICIES, get_policy
 from repro.launch.cell_configs import RECOMMENDED, recommended

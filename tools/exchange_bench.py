@@ -48,7 +48,7 @@ def main():
     results = {"arch": args.arch, "n_params": n_params}
     for method in ("f32", "int8", "blockfp8"):
         fn, in_sh, _ = make_pod_exchange(mesh, grad_shapes, method)
-        with mesh:
+        with jax.set_mesh(mesh):
             compiled = fn.lower(grad_shapes).compile()
         coll = parse_collectives(compiled.as_text(),
                                  default_group=n_pods)

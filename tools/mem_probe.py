@@ -19,7 +19,7 @@ def probe(tag, cfg, seq, batch):
     shape = InputShape("p", seq, batch, "train")
     batch_shape = registry.input_specs(cfg, shape)
     mesh = make_production_mesh(multi_pod=False)
-    with mesh:
+    with jax.set_mesh(mesh):
         step, _, _ = make_train_step(api, mesh, TrainConfig(), batch_shape)
         state_shape = jax.eval_shape(lambda k: init_state(api, k),
                                      jax.random.PRNGKey(0))
