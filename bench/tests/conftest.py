@@ -1,0 +1,9 @@
+"""Run with `pytest bench/tests` from the checkout's root: the program is
+imported from `src/`, the benchmark as the package `bench`."""
+import pathlib
+import sys
+
+ROOT = pathlib.Path(__file__).resolve().parents[2]
+for p in (ROOT / "src", ROOT):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
