@@ -3,6 +3,11 @@ RoPE (partial), sliding windows, gemma-2 attention softcap, QKV biases,
 qwen-3 QK-norm, bidirectional (encoder) and cross-attention modes, and a
 position-tagged KV cache that serves both full-attention decode and
 ring-buffer sliding-window decode.
+
+Named scopes (``jax.named_scope``; they reach each HLO op's ``op_name``
+metadata and a profiler trace, and cost nothing on the device):
+``attn.proj`` (q/k/v/o projections, RoPE), ``attn.core`` (scores,
+mask, softmax, weighted sum) and ``attn.kv_write`` (the cache update).
 """
 from __future__ import annotations
 
@@ -81,6 +86,7 @@ def init_cache(batch: int, capacity: int, cfg: AttnConfig,
     )
 
 
+@jax.named_scope("attn.proj")
 def _project_qkv(params, cfg: AttnConfig, x, positions, policy, path,
                  kv_input=None):
     spec = policy.spec_for
@@ -195,6 +201,7 @@ def _attend_chunked(cfg: AttnConfig, q, k, v, q_pos, k_pos, k_valid):
     return out[:, :sq].reshape(b, sq, hq * d).astype(v.dtype)
 
 
+@jax.named_scope("attn.core")
 def _attend(cfg: AttnConfig, q, k, v, q_pos, k_pos, k_valid):
     """Core attention dispatch: q (B,Sq,Hq,D); k/v (B,Sk,Hkv,D);
     q_pos (B,Sq), k_pos (B,Sk) absolute positions; k_valid (B,Sk)."""
@@ -217,7 +224,13 @@ def forward(params, cfg: AttnConfig, x, positions, policy: PrecisionPolicy,
     if kv_valid is None:
         kv_valid = jnp.ones(k.shape[:2], bool)
     out = _attend(cfg, q, k, v, positions, k_pos, kv_valid)
-    return mp_linear(params["wo"], out, policy.spec_for(f"{path}/wo"), path=f"{path}/wo")
+    return _project_out(params, out, policy, path)
+
+
+@jax.named_scope("attn.proj")
+def _project_out(params, out, policy, path):
+    return mp_linear(params["wo"], out, policy.spec_for(f"{path}/wo"),
+                     path=f"{path}/wo")
 
 
 def prefill(params, cfg: AttnConfig, x, positions, cache: KVCache,
@@ -239,6 +252,7 @@ def prefill(params, cfg: AttnConfig, x, positions, cache: KVCache,
         k_w, v_w, pos_w = k[:, -cap:], v[:, -cap:], positions[:, -cap:]
     start = (s - cap) % cap if s > cap else 0
 
+    @jax.named_scope("attn.kv_write")
     def write(buf, upd):
         buf = buf.astype(upd.dtype)
         first = upd[:, : cap - start]
@@ -253,8 +267,7 @@ def prefill(params, cfg: AttnConfig, x, positions, cache: KVCache,
         v=write(cache.v, v_w),
         pos=write(cache.pos, pos_w),
     )
-    return mp_linear(params["wo"], out, policy.spec_for(f"{path}/wo"), path=f"{path}/wo"), \
-        new_cache
+    return _project_out(params, out, policy, path), new_cache
 
 
 def prefill_chunk(params, cfg: AttnConfig, x, positions, valid,
@@ -276,19 +289,19 @@ def prefill_chunk(params, cfg: AttnConfig, x, positions, valid,
     few-slot engine path, not the sharded 32k prefill."""
     q, k, v = _project_qkv(params, cfg, x, positions, policy, path)
     cap = cache.k.shape[1]
-    slot = positions % cap                          # (B, S)
-    bidx = jnp.arange(x.shape[0], dtype=jnp.int32)[:, None]
-    vk = valid[..., None, None]
-    ck = cache.k.astype(k.dtype)
-    cv = cache.v.astype(v.dtype)
-    ck = ck.at[bidx, slot].set(jnp.where(vk, k, ck[bidx, slot]))
-    cv = cv.at[bidx, slot].set(jnp.where(vk, v, cv[bidx, slot]))
-    cpos = cache.pos.at[bidx, slot].set(
-        jnp.where(valid, positions, cache.pos[bidx, slot]))
+    with jax.named_scope("attn.kv_write"):
+        slot = positions % cap                          # (B, S)
+        bidx = jnp.arange(x.shape[0], dtype=jnp.int32)[:, None]
+        vk = valid[..., None, None]
+        ck = cache.k.astype(k.dtype)
+        cv = cache.v.astype(v.dtype)
+        ck = ck.at[bidx, slot].set(jnp.where(vk, k, ck[bidx, slot]))
+        cv = cv.at[bidx, slot].set(jnp.where(vk, v, cv[bidx, slot]))
+        cpos = cache.pos.at[bidx, slot].set(
+            jnp.where(valid, positions, cache.pos[bidx, slot]))
     new_cache = KVCache(ck, cv, cpos)
     out = _attend(cfg, q, ck, cv, positions, cpos, cpos >= 0)
-    return mp_linear(params["wo"], out, policy.spec_for(f"{path}/wo"), path=f"{path}/wo"), \
-        new_cache
+    return _project_out(params, out, policy, path), new_cache
 
 
 def decode_step(params, cfg: AttnConfig, x, pos, cache: KVCache,
@@ -301,12 +314,12 @@ def decode_step(params, cfg: AttnConfig, x, pos, cache: KVCache,
     positions = pos[:, None]
     q, k, v = _project_qkv(params, cfg, x, positions, policy, path)
     cap = cache.k.shape[1]
-    slot = pos % cap
-    bidx = jnp.arange(x.shape[0], dtype=jnp.int32)
-    ck = cache.k.astype(k.dtype).at[bidx, slot].set(k[:, 0])
-    cv = cache.v.astype(v.dtype).at[bidx, slot].set(v[:, 0])
-    cpos = cache.pos.at[bidx, slot].set(pos)
+    with jax.named_scope("attn.kv_write"):
+        slot = pos % cap
+        bidx = jnp.arange(x.shape[0], dtype=jnp.int32)
+        ck = cache.k.astype(k.dtype).at[bidx, slot].set(k[:, 0])
+        cv = cache.v.astype(v.dtype).at[bidx, slot].set(v[:, 0])
+        cpos = cache.pos.at[bidx, slot].set(pos)
     new_cache = KVCache(ck, cv, cpos)
     out = _attend(cfg, q, ck, cv, positions, cpos, cpos >= 0)
-    return mp_linear(params["wo"], out, policy.spec_for(f"{path}/wo"), path=f"{path}/wo"), \
-        new_cache
+    return _project_out(params, out, policy, path), new_cache

@@ -48,6 +48,7 @@ def layer_norm(x: jax.Array, weight: jax.Array, bias: Optional[jax.Array],
     return x.astype(dt)
 
 
+@jax.named_scope("norm")
 def apply_norm(kind: str, x, params, eps=1e-6):
     if kind == "rms":
         return rms_norm(x, params["w"], eps)

@@ -18,6 +18,7 @@ def init(key, d_model: int, d_ff: int, dtype=jnp.float32):
     }
 
 
+@jax.named_scope("mlp")
 def forward(params, x, policy, path: str, act: str = "silu"):
     fn = activation(act)
     g = mp_linear(params["w_gate"], x, policy.spec_for(f"{path}/w_gate"), path=f"{path}/w_gate")

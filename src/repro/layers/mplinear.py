@@ -409,13 +409,19 @@ def mp_linear(params, x: jax.Array, spec: PrecisionSpec,
 
     ``path`` is the projection's policy path (the same string the call
     site resolved the spec with) — only consumed by the calibration
-    hook (``collect_act_stats``) to key activation statistics."""
-    _note_act_absmax(path, x)
-    y = executor_for(spec.mode, _EXECUTOR_VARIANT)(
-        params["w"], x, spec, compute_dtype)
-    b = params.get("b")
-    if b is not None:
-        y = y + b.astype(y.dtype)
-    if spec.exact:
-        y = _rounded(y, compute_dtype)
-    return y.astype(compute_dtype)
+    hook (``collect_act_stats``) to key activation statistics.
+
+    Runs under the named scope ``mp_linear.<kind>``: the weight's
+    stored ``PreparedWeight.kind``, or ``dense`` for a raw weight."""
+    w = params["w"]
+    kind = w.kind if isinstance(w, PreparedWeight) else "dense"
+    with jax.named_scope(f"mp_linear.{kind}"):
+        _note_act_absmax(path, x)
+        y = executor_for(spec.mode, _EXECUTOR_VARIANT)(
+            w, x, spec, compute_dtype)
+        b = params.get("b")
+        if b is not None:
+            y = y + b.astype(y.dtype)
+        if spec.exact:
+            y = _rounded(y, compute_dtype)
+        return y.astype(compute_dtype)

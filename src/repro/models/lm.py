@@ -7,6 +7,13 @@ compile time, and remat policy are O(1) in depth; heterogeneous attention
 patterns (gemma-2 local/global alternation) scan over repeating *groups*
 of blocks. KV caches are stacked along the group axis and threaded as
 scan xs/ys.
+
+Every part of a served program runs under one ``jax.named_scope`` of a
+fixed vocabulary, which each HLO op carries in its ``op_name`` metadata
+and a profiler trace shows: ``embed``, ``norm``, ``attn.proj``,
+``attn.core``, ``attn.kv_write``, ``mlp``, ``head``, ``sample``, and
+``mp_linear.<kind>`` inside every projection (``kind`` the stored
+``PreparedWeight.kind``, ``dense`` for a raw weight).
 """
 from __future__ import annotations
 
@@ -101,6 +108,7 @@ def init(key, cfg: ModelConfig):
     return params
 
 
+@jax.named_scope("embed")
 def _embed(params, cfg: ModelConfig, tokens):
     x = jnp.take(params["embed"]["w"], tokens, axis=0)
     x = x.astype(jnp.dtype(cfg.compute_dtype))
@@ -109,6 +117,7 @@ def _embed(params, cfg: ModelConfig, tokens):
     return act.batch_seq(x)
 
 
+@jax.named_scope("head")
 def _head(params, cfg: ModelConfig, x):
     if cfg.tied_embeddings:
         w = params["embed"]["w"]

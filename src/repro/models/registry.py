@@ -374,7 +374,8 @@ def make_block_decode(api: "ModelAPI", n: int, policy=None,
                                            c.top_k, c.top_p)
                 keys = jnp.where(active[:, None], keys2, keys)
             else:
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                with jax.named_scope("sample"):
+                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             hit = (nxt[:, None] == c.stops).any(axis=-1) & active
             tok = jnp.where(active, nxt, tok)
             pos = jnp.where(active, pos + 1, pos)

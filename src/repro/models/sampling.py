@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("sample")
 def sample_tokens(keys, logits, temperature, top_k, top_p):
     """Select one token per batch row.
 

@@ -9,11 +9,14 @@ are deterministic:
 * :mod:`repro.obs.trace` — :class:`Tracer`: explicit-clock spans
   (request lifecycle, per-tick engine phases, JAX compile events)
   exported as Chrome trace-event JSON loadable in Perfetto
-  (https://ui.perfetto.dev). ``traced_jit`` wraps a jitted callable so
-  each compilation surfaces as a ``compile`` span.
+  (https://ui.perfetto.dev). An injected ``annotate`` sink (the engine
+  passes ``jax.profiler.TraceAnnotation``) repeats each tick-phase span
+  as ``engine.<phase>`` on a profiler capture's host plane, on the
+  device trace's clock. ``traced_jit`` wraps a jitted callable so each
+  compilation surfaces as a ``compile`` span.
 * :mod:`repro.obs.registry` — :class:`MetricsRegistry`: typed
-  counters/gauges/histograms plus rolling-window gauges sampled per
-  engine tick. The registry's counters back the engine's
+  counters/gauges/histograms plus rolling-window gauges (the engine
+  samples none). The registry's counters back the engine's
   ``metrics()["counters"]`` dict bit-compatibly through
   :class:`CountersView`. This module also owns the CANONICAL
   percentile-block schema (``PERCENTILES`` + ``percentile_block``)

@@ -18,15 +18,24 @@ microseconds — the Chrome trace-event format Perfetto
 (https://ui.perfetto.dev) and ``chrome://tracing`` load directly;
 ``tools/trace_report.py`` renders the same file as a terminal summary.
 
+An optional ``annotate`` sink puts the complete spans on a second
+timeline as well: ``span(name)`` also enters ``annotate(f"{process}.
+{name}")``, a context manager. The engine passes
+``jax.profiler.TraceAnnotation``, so a profiler capture shows
+``engine.admission``, ``engine.block_dispatch``, ... on its host plane,
+on the same clock as the device's ops. The sink is injected so this
+module stays free of jax.
+
 A disabled tracer (``enabled=False``) is free: ``span()`` hands back a
-shared no-op context manager and every record method returns before
-touching the clock, so the engine can construct one unconditionally.
+shared no-op context manager (and never calls ``annotate``) and every
+record method returns before touching the clock, so the engine can
+construct one unconditionally.
 """
 from __future__ import annotations
 
 import json
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, ContextManager, Dict, List, Optional
 
 # lane (tid) layout inside the single engine process (pid): the tick
 # phases share lane 0, request lifecycles get REQUEST_LANE_BASE + rid
@@ -52,9 +61,11 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """Open complete-span: records an ``X`` event on exit."""
+    """Open complete-span: records an ``X`` event on exit, and holds the
+    tracer's ``annotate`` context (if any) open in between."""
 
-    __slots__ = ("_tracer", "_name", "_cat", "_tid", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_cat", "_tid", "_args", "_t0",
+                 "_note")
 
     def __init__(self, tracer, name, cat, tid, args):
         self._tracer = tracer
@@ -63,15 +74,22 @@ class _Span:
         self._tid = tid
         self._args = args
         self._t0 = None
+        self._note = None
 
     def __enter__(self):
-        self._t0 = self._tracer.clock()
+        tr = self._tracer
+        if tr.annotate is not None:
+            self._note = tr.annotate(f"{tr.process}.{self._name}")
+            self._note.__enter__()
+        self._t0 = tr.clock()
         return self
 
     def __exit__(self, *exc):
-        self._tracer.complete(self._name, self._t0, self._tracer.clock(),
-                              cat=self._cat, tid=self._tid,
-                              args=self._args)
+        tr = self._tracer
+        tr.complete(self._name, self._t0, tr.clock(), cat=self._cat,
+                    tid=self._tid, args=self._args)
+        if self._note is not None:
+            self._note.__exit__(*exc)
         return False
 
 
@@ -81,14 +99,22 @@ class Tracer:
     ``max_events`` caps the in-memory buffer (a long-running engine
     must not grow without bound); events past the cap are counted in
     ``dropped`` and surfaced as an instant in the exported trace.
+
+    ``annotate``, when given, is a callable returning a context manager
+    (``jax.profiler.TraceAnnotation``, or a test's recorder): every
+    ``span(name)`` of an enabled tracer also enters
+    ``annotate(f"{process}.{name}")`` for its duration.
     """
 
     def __init__(self, clock: Callable[[], float] = time.monotonic,
                  enabled: bool = True, pid: int = 1,
-                 process: str = "engine", max_events: int = 200_000):
+                 process: str = "engine", max_events: int = 200_000,
+                 annotate: Optional[Callable[[str], ContextManager]] = None):
         self.clock = clock
         self.enabled = enabled
         self.pid = pid
+        self.process = process
+        self.annotate = annotate
         self.events: List[Dict] = []
         self.dropped = 0
         self._max_events = max_events
@@ -118,7 +144,8 @@ class Tracer:
 
     def span(self, name: str, cat: str = "engine", tid: int = TICK_LANE,
              args: Optional[Dict] = None):
-        """Context manager recording one complete (``X``) span."""
+        """Context manager recording one complete (``X``) span (and
+        entering ``annotate(f"{process}.{name}")`` while it is open)."""
         if not self.enabled:
             return _NULL_SPAN
         return _Span(self, name, cat, tid, args)

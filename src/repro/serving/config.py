@@ -55,12 +55,14 @@ class EngineConfig:
     Observability (``repro.obs``): ``trace=True`` records request
     lifecycle + tick-phase + compile spans on the engine's
     :class:`~repro.obs.Tracer` (``engine.dump_trace(path)`` exports
-    Chrome trace-event JSON; tracing off costs nothing).
-    ``cost_correction`` declares how a router should cost this replica:
-    ``"static"`` keeps the simulator estimate, ``"online"`` blends in
-    the measured :class:`~repro.obs.ReplicaStats` (EWMA tok/s over
-    per-tick samples with weight ``stats_alpha``; TTFT p95 and rolling
-    gauges over the last ``stats_window`` samples).
+    Chrome trace-event JSON) and repeats each tick phase as an
+    ``engine.<phase>`` ``jax.profiler.TraceAnnotation``, so a profiler
+    capture shows the phases beside the device's ops; tracing off costs
+    nothing per tick. ``cost_correction`` declares how a router should
+    cost this replica: ``"static"`` keeps the simulator estimate,
+    ``"online"`` blends in the measured :class:`~repro.obs.ReplicaStats`
+    (EWMA tok/s over per-tick samples with weight ``stats_alpha``; TTFT
+    p95 over the last ``stats_window`` first tokens).
     """
 
     batch_slots: int = 4
@@ -77,7 +79,7 @@ class EngineConfig:
     seed: int = 0                      # base PRNG seed for sampling
     trace: bool = False                # record spans (obs.Tracer)
     cost_correction: str = "static"    # static | online (router costing)
-    stats_window: int = 64             # rolling gauge / TTFT window
+    stats_window: int = 64             # ReplicaStats TTFT window
     stats_alpha: float = 0.2           # EWMA weight of newest rate sample
 
     def __post_init__(self):

@@ -133,9 +133,11 @@ class Request:
     done: bool = False
     error: Optional[str] = None        # set on terminal admission errors
     next_input: Optional[int] = None   # next token to feed decode
-    # timestamps stamped by scheduler/engine (engine clock domain)
+    # timestamps stamped by scheduler/engine (engine clock domain):
+    # submit <= admit <= prefill_done <= first_token split the TTFT
     submit_time: Optional[float] = None
     admit_time: Optional[float] = None
+    prefill_done_time: Optional[float] = None   # prompt fully consumed
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
     # per-request decoding parameters (greedy by default)
@@ -252,8 +254,11 @@ class ServingEngine:
                     "quant.calibrate dict) or serve exact int kernels")
         # observability: typed metrics behind a dict-compatible view
         # (metrics()["counters"] schema unchanged), a span tracer on the
-        # engine clock (free when config.trace is off) and the measured
-        # per-replica stats the router's online cost correction reads
+        # engine clock (free when config.trace is off) whose tick phases
+        # also enter jax.profiler annotations, so a profiler capture
+        # shows them as engine.<phase> beside the device's ops, and the
+        # measured per-replica stats the router's online cost
+        # correction reads
         self.registry = MetricsRegistry()
         for k in ("ticks", "decode_steps", "host_syncs",
                   "prefill_calls", "prefill_tokens",
@@ -261,14 +266,10 @@ class ServingEngine:
                   "short_blocks", "mid_block_admits", "eos_stops"):
             self.registry.counter(k)
         self.counters = self.registry.counters_view()
-        self.tracer = Tracer(clock=self.clock, enabled=self.config.trace)
+        self.tracer = Tracer(clock=self.clock, enabled=self.config.trace,
+                             annotate=jax.profiler.TraceAnnotation)
         self.stats = ReplicaStats(alpha=self.config.stats_alpha,
                                   window=self.config.stats_window)
-        w = self.config.stats_window
-        self._g_tok = self.registry.rolling("tok_per_tick", w)
-        self._g_queue = self.registry.rolling("queue_depth", w)
-        self._g_occ = self.registry.rolling("batch_occupancy", w)
-        self._g_short = self.registry.rolling("short_block", w)
         self._decode = traced_jit(
             jax.jit(_with_variant(
                 lambda p, tok, pos, c: api.decode_step(
@@ -482,8 +483,8 @@ class ServingEngine:
     def metrics(self) -> Dict:
         """Aggregate request latency metrics + engine counters (the
         ``counters`` block keeps the pre-registry plain-dict schema),
-        plus the rolling tick gauges and the measured replica stats the
-        router's online cost correction reads."""
+        plus the measured replica stats the router's online cost
+        correction reads."""
         from repro.serving.metrics import summarize_requests
         m = summarize_requests(self.completed.values())
         m["counters"] = dict(self.counters)
@@ -497,7 +498,6 @@ class ServingEngine:
         m["mid_block_admission"] = self.config.mid_block_admission
         m["eos_stopping"] = self.config.eos_stopping
         m["weight_bytes"] = self.weight_bytes()
-        m["gauges"] = self.registry.snapshot()["rolling"]
         m["replica_stats"] = self.stats.snapshot()
         m["trace"] = {"enabled": self.tracer.enabled,
                       "events": len(self.tracer.events),
@@ -639,6 +639,7 @@ class ServingEngine:
     def _req_decode_start(self, req: Request):
         """Request lifecycle transition: prompt fully consumed, the slot
         is decodable from the next tick on."""
+        req.prefill_done_time = self.clock()
         if self.tracer.enabled:
             self.tracer.req_end(req.rid, "prefill")
             self.tracer.req_begin(req.rid, "decode")
@@ -761,19 +762,10 @@ class ServingEngine:
 
     def _sample_tick(self, new_tokens: int):
         """Per-tick measured stats: the ReplicaStats EWMA the router's
-        online cost correction reads, plus the rolling gauges
-        ``metrics()['gauges']`` reports."""
-        now = self.clock()
+        online cost correction reads."""
         occupied = sum(r is not None for r in self.slot_req)
-        depth = len(self.scheduler)
-        self.stats.on_tick(now, new_tokens, depth,
+        self.stats.on_tick(self.clock(), new_tokens, len(self.scheduler),
                            active_slots=occupied)
-        self._g_tok.observe(now, new_tokens)
-        self._g_queue.observe(now, depth)
-        self._g_occ.observe(now, occupied / self.b)
-        if self.decode_block > 1:
-            self._g_short.observe(
-                now, 1.0 if self._last_block_short else 0.0)
 
     def step(self):
         """One engine tick: admit, advance prefilling slots one chunk,

@@ -98,6 +98,79 @@ class TestTracer:
         assert validate_chrome_trace(out) == []
 
 
+class _Recorder:
+    """Fake ``annotate`` sink: logs each enter and exit in order."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name):
+        log = self.log
+
+        class _Note:
+            def __enter__(self):
+                log.append(("enter", name))
+
+            def __exit__(self, *exc):
+                log.append(("exit", name))
+
+        return _Note()
+
+
+class TestAnnotateSink:
+    def test_spans_enter_process_dot_name_nested_in_order(self):
+        rec = _Recorder()
+        tr = Tracer(clock=FakeClock(), enabled=True, annotate=rec)
+        with tr.span("admission"):
+            pass
+        with tr.span("block_dispatch"):
+            with tr.span("host_sync"):
+                pass
+        assert rec.log == [
+            ("enter", "engine.admission"), ("exit", "engine.admission"),
+            ("enter", "engine.block_dispatch"),
+            ("enter", "engine.host_sync"), ("exit", "engine.host_sync"),
+            ("exit", "engine.block_dispatch")]
+        # the Chrome-JSON spans are recorded as before
+        xs = [e["name"] for e in tr.events if e["ph"] == "X"]
+        assert xs == ["admission", "host_sync", "block_dispatch"]
+
+    def test_process_name_prefixes_annotation(self):
+        rec = _Recorder()
+        tr = Tracer(clock=FakeClock(), enabled=True, process="replica3",
+                    annotate=rec)
+        with tr.span("harvest"):
+            pass
+        assert rec.log[0] == ("enter", "replica3.harvest")
+
+    def test_only_spans_annotate(self):
+        rec = _Recorder()
+        tr = Tracer(clock=FakeClock(), enabled=True, annotate=rec)
+        tr.complete("x", 0.0, 1.0)
+        tr.req_begin(1, "queued")
+        tr.req_end(1, "queued")
+        tr.instant("tick_done")
+        assert rec.log == []
+
+    def test_disabled_tracer_never_calls_annotate(self):
+        rec = _Recorder()
+        tr = Tracer(clock=FakeClock(), enabled=False, annotate=rec)
+        assert tr.span("admission") is _NULL_SPAN
+        _record_session(tr)
+        assert rec.log == [] and tr.events == []
+
+    def test_annotation_closes_when_the_span_raises(self):
+        rec = _Recorder()
+        tr = Tracer(clock=FakeClock(), enabled=True, annotate=rec)
+        with pytest.raises(ValueError):
+            with tr.span("harvest"):
+                raise ValueError("boom")
+        assert rec.log == [("enter", "engine.harvest"),
+                           ("exit", "engine.harvest")]
+        assert [e["name"] for e in tr.events if e["ph"] == "X"] == \
+            ["harvest"]
+
+
 class TestTracedJit:
     def test_compile_span_once_per_signature(self):
         import jax

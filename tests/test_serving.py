@@ -93,6 +93,31 @@ class TestEngineDrain:
         # a 1-token prompt needs no prefill call at all
         assert eng.counters["prefill_calls"] == 0
 
+    @pytest.mark.parametrize("prefill", ["batched", "teacher"])
+    def test_lifecycle_stamps_split_ttft(self, lm_setup, prefill):
+        """submit <= admit <= prefill_done <= first_token for every
+        request: chunked prompts of several waves, a one-token prompt,
+        and requests that queue behind full slots."""
+        cfg = lm_setup[0]
+        eng = ServingEngine(
+            cfg, lm_setup[1], lm_setup[2],
+            config=EngineConfig(batch_slots=2, cache_len=64,
+                                prefill=prefill, prefill_chunk=4),
+            clock=_FakeClock())
+        reqs = _requests(cfg, [11, 1, 6, 9, 1], [2, 3, 2, 1, 2])
+        for r in reqs:
+            eng.submit(r)
+        eng.run_until_drained()
+        for r in reqs:
+            assert r.done and r.error is None
+            assert (r.submit_time <= r.admit_time <= r.prefill_done_time
+                    <= r.first_token_time), r.rid
+        # the 11-token prompt took three 4-token waves on the fast path
+        long = reqs[0]
+        assert long.prefill_done_time > long.admit_time
+        # queued behind the two slots: admitted after it was submitted
+        assert reqs[4].admit_time > reqs[4].submit_time
+
     def test_oversized_requests_truncate_instead_of_rejecting(
             self, lm_setup):
         """Chunked prefill lifted the old ``prompt + generation <=
@@ -1042,12 +1067,39 @@ class TestServingObservability:
         # bit-compat: the counters block is the plain pre-refactor dict
         assert m["counters"] == dict(eng.counters)
         assert isinstance(m["counters"], dict)
-        assert set(m["gauges"]) >= {"tok_per_tick", "queue_depth",
-                                    "batch_occupancy"}
-        assert m["gauges"]["tok_per_tick"]["n"] > 0
         assert m["replica_stats"]["ticks"] == m["counters"]["ticks"]
         assert m["replica_stats"]["ttft_samples"] == 3
         assert m["replica_stats"]["tok_per_s"] > 0
         assert m["queue_highwater"] == 3
         assert m["trace"] == {"enabled": False, "events": 0,
                               "dropped": 0}
+        assert "gauges" not in m
+
+    @pytest.mark.parametrize("trace", [False, True])
+    def test_phase_annotations_only_when_tracing(self, lm_setup,
+                                                 monkeypatch, trace):
+        """The engine's tick phases enter jax.profiler annotations named
+        engine.<phase> when tracing is on; with it off no annotation is
+        made and no rolling gauge is sampled per tick."""
+        import contextlib
+
+        import jax
+        entered = []
+
+        def record(name):
+            entered.append(name)
+            return contextlib.nullcontext()
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", record)
+        eng = self._run(lm_setup, trace=trace)
+        assert eng.registry.snapshot()["rolling"] == {}
+        if not trace:
+            assert entered == []
+            return
+        assert set(entered) == {
+            "engine.admission", "engine.prefill_dispatch",
+            "engine.block_dispatch", "engine.host_sync",
+            "engine.harvest"}
+        spans = [e["name"] for e in eng.tracer.events if e["ph"] == "X"
+                 and not e["name"].startswith("compile:")]
+        assert entered == [f"engine.{n}" for n in spans]

@@ -19,6 +19,18 @@ validate_chrome_trace``, non-zero exit on errors), then prints:
   * request lanes: per-stage durations (queued / prefill / decode) of
     each request's B/E pairs and its first-token/finished instants;
   * the top individual spans by duration.
+
+The same tick phases also appear in a JAX profiler capture, beside the
+device's ops: with tracing on, each phase span enters a
+``jax.profiler.TraceAnnotation`` named ``engine.<phase>``. Capture a
+few ticks with ``jax.profiler.start_trace(dir)`` / ``stop_trace()``
+around ``engine.step()`` and open ``dir`` in TensorBoard's profile
+plugin or Perfetto (``create_perfetto_trace=True``), or read the
+``.xplane.pb`` with ``jax.profiler.ProfileData``: the phases lie on the
+``/host:CPU`` plane, the device's ops on ``/device:TPU:0``'s "XLA Ops"
+line, and each op's ``op_name`` metadata in the compiled program
+(``jax.jit(f).lower(...).compile().as_text()``) carries the model's
+named scopes (``attn.core``, ``mlp``, ``mp_linear.int8``, ...).
 """
 import argparse
 import collections
