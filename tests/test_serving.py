@@ -34,6 +34,30 @@ def lm_setup():
     return cfg, api, params
 
 
+@pytest.fixture(scope="module", params=["qwen2-0.5b", "gemma2-9b"])
+def decoding_engine(request):
+    """Six live decode slots with written caches, on a dense model and
+    on one whose local/global layers keep two cache groups (the local
+    one a ring), with calibrated int8 as the chip benchmark serves."""
+    import jax
+
+    from repro.models import registry
+    cfg = dataclasses.replace(reduced(request.param),
+                              precision_policy="int8_serving")
+    api = registry.build(cfg)
+    eng = ServingEngine(cfg, api, api.init(jax.random.PRNGKey(0)),
+                        config=EngineConfig(batch_slots=6, cache_len=32,
+                                            prefill_chunk=8,
+                                            act_calibration="auto"))
+    assert len(eng.caches) == (2 if cfg.attn_pattern ==
+                               "alt_local_global" else 1)
+    for r in _requests(cfg, range(5, 11), [40] * 6):
+        eng.submit(r)
+    eng.step()
+    eng.step()
+    return eng
+
+
 def _engine(lm_setup, **kw):
     cfg, api, params = lm_setup
     kw.setdefault("batch_slots", 3)
@@ -223,6 +247,95 @@ class TestBatchedPrefill:
         np.testing.assert_allclose(first_logits(fast),
                                    first_logits(slow),
                                    rtol=0.1, atol=0.1)
+
+    @pytest.mark.parametrize("n_prefill", range(1, 7))
+    def test_bucket_wave_equals_full_width_wave(self, decoding_engine,
+                                                n_prefill):
+        """A wave on the smallest power-of-two bucket of rows that holds
+        the prefilling slots writes every cache leaf (k, v, pos), in
+        every row, exactly as the full-width ``api.prefill_chunk`` wave
+        does on the same caches. The bucket's pad rows are live decode
+        slots whose cache rows hold their prompts and tokens."""
+        import jax
+        import jax.numpy as jnp
+        eng = decoding_engine
+        base, live, pos = eng.caches, list(eng.slot_req), eng.pos.copy()
+        assert all(r is not None and r.next_input is not None
+                   for r in live)
+        chunk = eng.prefill_chunk
+        tokens = np.zeros((eng.b, chunk), np.int32)
+        offs = np.zeros(eng.b, np.int32)
+        lens = np.zeros(eng.b, np.int32)
+        rng = np.random.default_rng(n_prefill)
+        # scattered slots, offsets and lengths: 1, 0, 5, 4, 3, 2
+        for j, s in enumerate((5 * j + 1) % eng.b
+                              for j in range(n_prefill)):
+            off, take = chunk * (s % 2), chunk - 3 * (j % 2)
+            req = Request(rid=100 + s, prompt=rng.integers(
+                0, eng.cfg.vocab, off + take + 1, dtype=np.int32))
+            req.tokens, req.prefill_pos = list(req.prompt), off
+            eng.slot_req[s] = req
+            tokens[s, :take] = req.prompt[off:off + take]
+            offs[s], lens[s] = off, take
+        rows0 = eng.counters["prefill_rows"]
+        try:
+            assert eng._prefill_tick()
+            got = eng.caches
+        finally:
+            eng.caches, eng.slot_req[:], eng.pos[:] = base, live, pos
+        want = eng._prefill_chunk_fn(eng.params, jnp.array(tokens),
+                                     jnp.array(offs), jnp.array(lens), base)
+        assert eng.counters["prefill_rows"] - rows0 == min(
+            b for b in (1, 2, 4, 6) if b >= n_prefill)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                        strict=True):
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                          np.asarray(b, np.float32))
+
+    def test_waves_take_the_smallest_bucket_and_never_compile(self,
+                                                              lm_setup):
+        """Each wave dispatches the smallest bucket of {1, 2, 4} rows
+        that holds its prefilling slots (``prefill_rows`` sums them),
+        and every bucket compiles on the first prefill tick: no wave
+        after it compiles, whichever bucket it takes."""
+        cfg = lm_setup[0]
+        eng = _engine(lm_setup, batch_slots=4, prefill_chunk=4,
+                      trace=True)
+        reqs = _requests(cfg, [30, 6, 20, 9, 25, 14], [3] * 6)
+        TestContinuousServing()._drive(eng, reqs, [0, 1, 1, 2, 2, 6])
+        events = [e for e in eng.tracer.events if e["ph"] == "X"]
+        waves = [e["args"] for e in events
+                 if e["name"] == "prefill_dispatch"]
+        for w in waves:
+            assert w["rows"] == min(b for b in (1, 2, 4)
+                                    if b >= w["slots"])
+        assert {w["rows"] for w in waves} == {1, 2, 4}
+        assert any(w["rows"] > w["slots"] for w in waves)   # padded
+        assert eng.counters["prefill_rows"] == sum(w["rows"] for w in waves)
+        assert eng.counters["prefill_calls"] == len(waves)
+        names = [e["name"] for e in events]
+        first = names.index("prefill_dispatch")
+        assert names[:first].count("compile:prefill_chunk") == 3
+        assert "compile:prefill_chunk" not in names[first:]
+
+    def test_warm_dispatches_write_and_count_nothing(self, lm_setup):
+        """The first prefill tick compiles every bucket with waves of
+        no valid token: only the tick's own wave is counted, and only
+        its slot's positions carry cache tags afterwards."""
+        cfg = lm_setup[0]
+        eng = _engine(lm_setup, prefill_chunk=4)
+        eng.submit(_requests(cfg, [7], [2])[0])
+        eng._admit()
+        assert eng._prefill_tick()
+        assert eng._prefill_buckets == [1, 2, 3]
+        assert (eng.counters["prefill_calls"], eng.counters["prefill_tokens"],
+                eng.counters["prefill_rows"]) == (1, 4, 1)
+        want = np.full((eng.b, eng.cache_len), -1)
+        want[0, :4] = np.arange(4)
+        for cache in eng.caches.values():
+            for group in np.asarray(cache.pos):
+                np.testing.assert_array_equal(group, want)
 
     def test_batched_rejected_for_recurrent_families(self):
         """Recurrent state is not position-tagged: padded prefill would
