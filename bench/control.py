@@ -4,9 +4,11 @@
 
 For each seed, in one process: one run of the cell as the benchmark
 makes it (short window, the cell's own load), then the reference over
-the sampled prompts and served tokens, and the control at the same
-positions: the reference with every block projection quantized to the
-configuration's `control_weight_bits` (the precision below the tier's).
+the sampled prompts and served tokens (with the weights at the file's
+`stored_weight_bits` where it states them), and the control at the same
+positions: the drawn weights with every block projection quantized to
+the configuration's `control_weight_bits` (the precision below the
+tier's), its gaps read against the same reference.
 Prints one JSON line per seed with the program's and the control's
 numbers and verdicts under the cell's limits (`harness.checks`: the
 program's has to be true, the control's false), and a last line with the

@@ -5,9 +5,32 @@ then compare a sample of what it served with the plain reference.
 Everything that belongs to one configuration, traffic mix or per-layer
 metric is found by name: `bench/configs/<config>.json` (via
 BENCHMARK.json), `bench/traffic/<mix>.json`, `bench/generators/<name>.py`,
-`bench/metrics/<metric>.py`, `bench/reference/<family>.py` and
+`bench/metrics/<metric>.py`, `bench/reference/<module>.py` and
 `bench/limits/<cell>.json`. Adding a cell adds such files and entries in
 BENCHMARK.json; no file here changes.
+
+The harness knows no architecture. A configuration file names its
+reference module by `reference` (by default its `family`), and that
+module, which imports nothing of the program, gives:
+
+- `make_params(c, seed, dtype)`: the weight tree from the seed, on the
+  device, laid out as the program takes it;
+- `served_gaps(params, c, prompt, served, control_bits)`: the gaps of
+  the served tokens below the reference's best logit, and the control's;
+- `program_fields(c)`: the program's `repro.configs.base.ModelConfig`
+  fields the file states, as plain data (a nested dataclass such as
+  `moe` as a dict). `model_config` lays them over the program's entry
+  for `c["arch"]`. The tier and the dtype (`DEPLOYMENT_FIELDS`) are the
+  file's; any other field, a size or a mechanism, that departs from the
+  entry is taken only where the file's `reduced` names its key, and the
+  file is refused otherwise;
+- `FILE_KEYS` (optional): the file's key each such field is read from
+  (`{"n_layers": "num_hidden_layers"}`, a nested field as `moe.top_k`);
+  a field it does not map is named in `reduced` by its own name;
+- the work a step needs: `prefill_ops(c, start, stop)`,
+  `decode_ops(c, first_pos, steps)` (operations, for `step_mfu_pct`)
+  and `projection_calls(c)`, the (K, N, calls) of the block projections
+  one step makes (`fused_dequant_mm_roofline`).
 """
 from __future__ import annotations
 
@@ -85,30 +108,55 @@ def load_cell(workload: str, root: pathlib.Path = ROOT) -> Cell:
                for m in spec["end_to_end"] + spec["per_layer"]})
 
 
+# ModelConfig fields a deployment sets whatever the architecture's entry
+# holds: the tier and the weights' dtype
+DEPLOYMENT_FIELDS = ("precision_policy", "param_dtype")
+
+
+def _lay_over(name: str, base, want, reduced, keys: dict,
+              differ: List[str]):
+    """``want`` (plain data) laid over the entry's value ``base``: a dict
+    over a dataclass field by field (as ``name.field``); any other value
+    that departs from ``base`` is taken only where ``reduced`` holds its
+    key (``keys[name]``, else ``name``), and is noted in ``differ``
+    otherwise."""
+    if dataclasses.is_dataclass(base) and isinstance(want, dict):
+        return dataclasses.replace(base, **{
+            k: _lay_over(f"{name}.{k}", getattr(base, k), v, reduced, keys,
+                         differ)
+            for k, v in want.items()})
+    if isinstance(base, tuple) and isinstance(want, list):
+        want = tuple(want)
+    key = keys.get(name, name)
+    if want != base and key not in reduced:
+        differ.append(f"{name}: program {base!r}, file {want!r} "
+                      f"({key} not in reduced)")
+    return want
+
+
 def model_config(c: dict):
     """The program's ModelConfig for configuration file ``c``: the
-    architecture's entry with the file's sizes, tier and dtype."""
+    reference module's `program_fields` laid over the program's entry for
+    ``c["arch"]``. Refused where a field departs from the entry and the
+    file's `reduced` does not name it, where the stored weight bits are
+    not the tier's, or where the vocabulary is padded."""
     from repro.configs import get_config
     base = get_config(c["arch"])
-    cfg = dataclasses.replace(
-        base,
-        n_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        n_heads=c["num_attention_heads"],
-        n_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
-        d_ff=c["intermediate_size"], vocab=c["vocab_size"],
-        qkv_bias=c["attention_bias"], rope_theta=c["rope_theta"],
-        rotary_pct=c["partial_rotary_factor"],
-        tied_embeddings=c["tie_word_embeddings"],
-        precision_policy=c["precision_policy"],
-        param_dtype=c["param_dtype"])
-    want = dict(family=c["family"], act=c["hidden_act"], norm="rms",
-                attn_pattern="full", moe=None, post_norms=False,
-                logit_softcap=None, attn_softcap=None, qk_norm=False,
-                attn_scale=None)
-    got = {k: getattr(cfg, k) for k in want}
-    if got != want:
+    module = reference_module(c)
+    reduced = set(c.get("reduced", ()))
+    keys = getattr(module, "FILE_KEYS", {})
+    differ: List[str] = []
+    fields = {k: v if k in DEPLOYMENT_FIELDS
+              else _lay_over(k, getattr(base, k), v, reduced, keys, differ)
+              for k, v in module.program_fields(c).items()}
+    if differ:
         raise BenchError(f"{c['name']}: the program's {c['arch']} differs "
-                         f"from the file: {got} != {want}")
+                         f"from the file: " + "; ".join(differ))
+    stored = c.get("stored_weight_bits")
+    if stored is not None and stored != weight_bits(c):
+        raise BenchError(f"{c['name']}: stored_weight_bits {stored}, but "
+                         f"{c['precision_policy']} stores {weight_bits(c)}")
+    cfg = dataclasses.replace(base, **fields)
     if cfg.padded_vocab != cfg.vocab:
         raise BenchError(f"{c['name']}: vocab {cfg.vocab} is padded")
     return cfg
@@ -122,7 +170,9 @@ def engine_config(c: dict, trace: bool):
 
 
 def reference_module(c: dict):
-    return importlib.import_module(f"bench.reference.{c['family']}")
+    """`bench/reference/<c["reference"]>.py`, by default the family's."""
+    name = c.get("reference", c["family"])
+    return importlib.import_module(f"bench.reference.{name}")
 
 
 def generator(traffic: dict):
@@ -251,9 +301,9 @@ def drive(engine, plan, c: dict, seconds: float,
     a request's first token and each later one are stamped when the step
     that harvested them returns."""
     import jax
-    from bench import flops
     from repro.serving import Request
     from repro.serving.scheduler import SchedulerFull
+    work = reference_module(c)
     tracks: List[Track] = []
     inflight: List[Track] = []
     ticks: List[Tick] = []
@@ -324,12 +374,12 @@ def drive(engine, plan, c: dict, seconds: float,
         for tr in inflight:
             r = tr.req
             if r.prefill_pos > tr.prefill_pos:
-                ops += flops.prefill_ops(c, tr.prefill_pos, r.prefill_pos)
+                ops += work.prefill_ops(c, tr.prefill_pos, r.prefill_pos)
                 tr.prefill_pos = r.prefill_pos
             n = r.new_tokens
             if n > tr.n_tok:
-                ops += flops.decode_ops(c, len(r.prompt) - 1 + tr.n_tok,
-                                        n - tr.n_tok)
+                ops += work.decode_ops(c, len(r.prompt) - 1 + tr.n_tok,
+                                       n - tr.n_tok)
                 harvested += n - tr.n_tok
                 new_tokens.append(n - tr.n_tok)
                 tr.n_tok = n

@@ -1,9 +1,10 @@
 """Whole model step: the operations the traced ticks needed (valid
 prompt tokens and active decode rows, attention over the keys each
-query sees, `bench/flops.py`) over the device time of the served
-programs, over the chip's int8 peak: the narrowest arithmetic the int
-tiers may use (v5e lists no int4 peak), so the share stays under 100%
-once the kernels feed int8 to the MXU. Moves tpot_p90_ms."""
+query sees, counted by the configuration's reference module) over the
+device time of the served programs, over the chip's int8 peak: the
+narrowest arithmetic the int tiers may use (v5e lists no int4 peak), so
+the share stays under 100% once the kernels feed int8 to the MXU.
+Moves tpot_p90_ms."""
 from bench import programs
 from bench import trace as tr
 

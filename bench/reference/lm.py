@@ -16,12 +16,27 @@ in), laid out as the served program takes them. The benchmark hands that
 tree to the program and, after the window, draws it again for this
 reference: the reference takes nothing that the program made.
 
+Where the file states `stored_weight_bits`, the deployment stores every
+block projection at that many bits, symmetric per output channel, and the
+reference computes with those weights (`fake_quant`; the harness refuses
+a file whose bits are not its tier's); the embedding, norms and head
+stay as drawn, as the int tiers keep them. Its activations stay float32:
+the int8 activation scales the served path adds are one of the
+departures the cell's limits cover.
+
+Besides the reference, the module gives the harness what belongs to the
+architecture (`bench/harness.py` states the contract): `program_fields`,
+the program's `ModelConfig` fields the file states, `FILE_KEYS`, the
+file's key of each, and the work a step needs (`projection_shapes`,
+`projection_calls`, `prefill_ops`, `decode_ops`).
+
 This module imports nothing of the program.
 """
 from __future__ import annotations
 
 import functools
 import math
+from typing import List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -51,6 +66,35 @@ def sizes(c: dict) -> dict:
     return dict(d=d, hd=hd, hq=c["num_attention_heads"],
                 hkv=c["num_key_value_heads"], f=c["intermediate_size"],
                 v=c["vocab_size"], layers=c["num_hidden_layers"])
+
+
+# the file's key each field of `program_fields` is read from: a field
+# that departs from the program's entry is taken where `reduced` names it
+FILE_KEYS = dict(
+    n_layers="num_hidden_layers", d_model="hidden_size",
+    n_heads="num_attention_heads", n_kv_heads="num_key_value_heads",
+    d_ff="intermediate_size", vocab="vocab_size", act="hidden_act",
+    qkv_bias="attention_bias", rotary_pct="partial_rotary_factor",
+    tied_embeddings="tie_word_embeddings")
+
+
+def program_fields(c: dict) -> dict:
+    """The program's `ModelConfig` fields that file ``c`` states: its
+    sizes, tier and dtype, and every mechanism of the dense block this
+    module computes (RMSNorm, full causal attention, no experts, no
+    q/k norm, no post-norms, no softcaps, the default attention scale)."""
+    s = sizes(c)
+    return dict(
+        family=c["family"], n_layers=s["layers"], d_model=s["d"],
+        n_heads=s["hq"], n_kv_heads=s["hkv"], head_dim=s["hd"],
+        d_ff=s["f"], vocab=s["v"], act=c["hidden_act"], norm="rms",
+        qkv_bias=c["attention_bias"], qk_norm=False,
+        rope_theta=c["rope_theta"], rotary_pct=c["partial_rotary_factor"],
+        attn_pattern="full", logit_softcap=None, attn_softcap=None,
+        post_norms=False, tied_embeddings=c["tie_word_embeddings"],
+        attn_scale=None, moe=None,
+        precision_policy=c["precision_policy"],
+        param_dtype=c["param_dtype"])
 
 
 def param_shapes(c: dict) -> dict:
@@ -203,7 +247,8 @@ def logits_at(params, c: dict, tokens, rows, weight_bits=None):
     """float32 logits (len(rows), vocab) at the given positions of
     ``tokens`` (positions count from 0). ``weight_bits`` fake-quantizes
     every block projection per output channel to that many bits (the
-    control); the embedding, norms and head stay as drawn."""
+    stored weights, or the control's); the embedding, norms and head
+    stay as drawn."""
     s = sizes(c)
     n = len(tokens)
     padded = -(-n // Q_BLOCK) * Q_BLOCK
@@ -234,18 +279,80 @@ def _gap_of(ref, chosen):
 def served_gaps(params, c: dict, prompt, served, control_bits=None):
     """Gaps by which each served token's reference logit lies below the
     reference's best, at its position; with ``control_bits`` also the
-    gaps of the tokens that the control (the reference at that weight
-    precision) puts first at the same positions.
+    gaps of the tokens that the control (the reference with the drawn
+    weights at that precision) puts first at the same positions. The
+    reference computes with the weights the deployment stores: at the
+    file's `stored_weight_bits` where it states them, else as drawn.
 
     Returns (program gaps, control gaps or None), numpy float32."""
     prompt = np.asarray(prompt, np.int32)
     served = np.asarray(served, np.int32)
     tokens = np.concatenate([prompt, served[:-1]])
     rows = np.arange(len(prompt) - 1, len(tokens))
-    ref = logits_at(params, c, tokens, rows)
+    ref = logits_at(params, c, tokens, rows,
+                    weight_bits=c.get("stored_weight_bits"))
     prog = np.asarray(_gap_of(ref, jnp.asarray(served)))
     ctrl = None
     if control_bits is not None:
         low = logits_at(params, c, tokens, rows, weight_bits=control_bits)
         ctrl = np.asarray(_gap_of(ref, jnp.argmax(low, axis=-1)))
     return prog, ctrl
+
+
+# ------------------------------------------------- the work a step needs
+#
+# Counts are of the work the algorithm needs, from the file's own sizes:
+# valid prompt tokens and active decode rows, attention over the keys a
+# query may see (causal), never padded rows, padded K or masked slots. A
+# multiply-add counts as 2 operations. Biases, norms, rotary embedding
+# and softmax are left out: they are under 1% of the operations at these
+# widths.
+
+def projection_shapes(c: dict) -> List[Tuple[int, int]]:
+    """(K, N) of each projection of one layer: wq, wk, wv, wo, w_gate,
+    w_up, w_down."""
+    s = sizes(c)
+    d, f, q, kv = s["d"], s["f"], s["hq"] * s["hd"], s["hkv"] * s["hd"]
+    return [(d, q), (d, kv), (d, kv), (q, d), (d, f), (d, f), (f, d)]
+
+
+def projection_calls(c: dict) -> List[Tuple[int, int, int]]:
+    """(K, N, calls) of the block projections one step of the model
+    makes over every row it computes: each shape once a layer."""
+    layers = c["num_hidden_layers"]
+    return [(k, n, layers) for k, n in projection_shapes(c)]
+
+
+def projection_ops_per_token(c: dict) -> int:
+    """Operations of every block projection for one token."""
+    layers = c["num_hidden_layers"]
+    return 2 * layers * sum(k * n for k, n in projection_shapes(c))
+
+
+def head_ops_per_token(c: dict) -> int:
+    return 2 * c["hidden_size"] * c["vocab_size"]
+
+
+def attention_ops(c: dict, first_pos: int, count: int) -> int:
+    """Operations of q.k and p.v for ``count`` queries at positions
+    ``first_pos``..``first_pos + count - 1``, each over the keys at
+    positions up to its own (position p sees p + 1 keys)."""
+    if count <= 0:
+        return 0
+    s = sizes(c)
+    keys = count * first_pos + count * (count + 1) // 2
+    return 2 * 2 * s["layers"] * s["hq"] * s["hd"] * keys
+
+
+def prefill_ops(c: dict, start: int, stop: int) -> int:
+    """Prompt positions start..stop-1 written to the cache (no head:
+    the prefill program computes no logits)."""
+    n = stop - start
+    return n * projection_ops_per_token(c) + attention_ops(c, start, n)
+
+
+def decode_ops(c: dict, first_pos: int, steps: int) -> int:
+    """``steps`` decode steps of one request, the first with its input
+    token at position ``first_pos``; each step computes the head."""
+    per = projection_ops_per_token(c) + head_ops_per_token(c)
+    return steps * per + attention_ops(c, first_pos, steps)

@@ -3,7 +3,9 @@ tiny cell on the CPU), the reference with its block projections at the
 precision below the tier's reads a widest gap at least three times the
 program's on every seed, and the harness's own comparison, under the
 cell's limits, finds it not correct where the program is correct. The
-chip readings behind the cells' own limits are in PERF.md."""
+int4 file stores its weights at 4 bits, so its reference computes with
+the program's int4 codes and its int3 control with the drawn weights.
+The chip readings behind the cells' own limits are in PERF.md."""
 import time
 
 import jax
@@ -14,9 +16,12 @@ from bench.tests.test_run import PEAKS
 from bench.tests.tiny import tiny_cell
 
 
-@pytest.mark.parametrize("seed", [11, 2**31 + 3, 424242])
-def test_control_fails_where_the_program_passes(seed):
-    cell = tiny_cell(limit=0.5)
+@pytest.mark.parametrize("config,seed", [
+    ("qwen2-0.5b-int8", 11), ("qwen2-0.5b-int8", 2**31 + 3),
+    ("qwen2-0.5b-int8", 424242), ("glm4-9b-int4", 12),
+    ("glm4-9b-int4", 2**31 + 5), ("glm4-9b-int4", 777)])
+def test_control_fails_where_the_program_passes(config, seed):
+    cell = tiny_cell(config, limit=0.5)
     out = harness.run_cell(cell, seed, 3.0, False, time.monotonic(),
                            jax.devices()[0], harness.CompileWatch(), PEAKS,
                            control_bits=cell.config["control_weight_bits"])

@@ -1,8 +1,9 @@
 """A run with the timed path broken underneath must come out not
 correct: the harness drives a tiny cell on the CPU (the chip check
 skipped) with one fault planted in the program, and the comparison with
-the reference catches it. The limit is the tiny cell's own: sound tiny
-runs read a widest gap under 0.2, these faults above 1."""
+the reference catches it, for the int8 and the int4 configuration. The
+limit is the tiny cell's own: sound tiny runs read a widest gap under
+0.35, these faults above 1."""
 import time
 
 import jax
@@ -15,11 +16,14 @@ from bench.tests.tiny import tiny_cell
 LIMIT = 0.5
 
 
-def _run(seed=5):
+CONFIGS = ["qwen2-0.5b-int8", "glm4-9b-int4"]
+
+
+def _run(config, seed=5):
     # a closed loop keeps every slot busy, so a fault in some slots
     # reaches the sampled requests
-    cell = tiny_cell(limit=LIMIT, arrivals={"kind": "closed", "clients": 4,
-                                            "pool": 64})
+    cell = tiny_cell(config, limit=LIMIT,
+                     arrivals={"kind": "closed", "clients": 4, "pool": 64})
     out = harness.run_cell(cell, seed, 3.0, False,
                            time.monotonic(), jax.devices()[0],
                            harness.CompileWatch(), PEAKS)
@@ -69,14 +73,16 @@ def _half_batch(monkeypatch):
     monkeypatch.setattr(lm, "prefill_chunk", chunk)
 
 
-def test_sound_run_is_correct():
-    ok, gap = _run()
+@pytest.mark.parametrize("config", CONFIGS)
+def test_sound_run_is_correct(config):
+    ok, gap = _run(config)
     assert ok and gap < LIMIT
 
 
+@pytest.mark.parametrize("config", CONFIGS)
 @pytest.mark.parametrize("fault", [_altered_token, _state_unchanged,
                                    _half_batch])
-def test_fault_is_not_correct(fault, monkeypatch):
+def test_fault_is_not_correct(fault, config, monkeypatch):
     fault(monkeypatch)
-    ok, gap = _run()
+    ok, gap = _run(config)
     assert not ok, f"{fault.__name__}: widest gap {gap}"

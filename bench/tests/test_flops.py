@@ -1,25 +1,13 @@
-"""bench/flops.py against counts worked out by hand."""
-import json
-import pathlib
-
+"""The work counts of the configurations' reference modules and
+bench/flops.py's least times, against counts worked out by hand."""
 import pytest
 
-from bench import flops
-
-CONFIGS = pathlib.Path(__file__).resolve().parents[1] / "configs"
-
-
-# GLM-4-9B's published widths (hf:THUDM/glm-4-9b), 10 of its 40 layers
-GLM4_STAGE = {"hidden_size": 4096, "num_attention_heads": 32,
-              "num_key_value_heads": 2, "head_dim": 128,
-              "intermediate_size": 13696, "vocab_size": 151552,
-              "num_hidden_layers": 10}
+from bench import flops, harness
+from bench.tests.tiny import config_file as _config
 
 
-def _config(name):
-    if name == "glm4-9b-int4":
-        return dict(GLM4_STAGE)
-    return json.loads((CONFIGS / f"{name}.json").read_text())
+def _work(c):
+    return harness.reference_module(c)
 
 
 # per layer: d*q + 2*d*kv + q*d + 3*d*f multiply-adds, two ops each
@@ -34,23 +22,25 @@ def _config(name):
 ])
 def test_per_token_counts(name, proj, head, attn_one):
     c = _config(name)
-    assert flops.projection_ops_per_token(c) == proj
-    assert flops.head_ops_per_token(c) == head
-    assert flops.attention_ops(c, 0, 1) == attn_one
+    work = _work(c)
+    assert work.projection_ops_per_token(c) == proj
+    assert work.head_ops_per_token(c) == head
+    assert work.attention_ops(c, 0, 1) == attn_one
 
 
 def test_attention_is_causal_over_positions():
     c = _config("qwen2-0.5b-int8")
-    one = flops.attention_ops(c, 0, 1)
+    work = _work(c)
+    one = work.attention_ops(c, 0, 1)
     # queries at 10, 11, 12 see 11 + 12 + 13 = 36 keys
-    assert flops.attention_ops(c, 10, 3) == 36 * one
-    assert flops.attention_ops(c, 5, 0) == 0
+    assert work.attention_ops(c, 10, 3) == 36 * one
+    assert work.attention_ops(c, 5, 0) == 0
     # a prompt written in two chunks costs what it costs in one
-    assert (flops.prefill_ops(c, 0, 32) + flops.prefill_ops(c, 32, 64)
-            == flops.prefill_ops(c, 0, 64))
+    assert (work.prefill_ops(c, 0, 32) + work.prefill_ops(c, 32, 64)
+            == work.prefill_ops(c, 0, 64))
     # decode: 4 steps from position 100 (keys 101..104), each with a head
     per = 715_653_120 + 272_269_312
-    assert flops.decode_ops(c, 100, 4) == 4 * per + (101 + 102 + 103 + 104) * one
+    assert work.decode_ops(c, 100, 4) == 4 * per + (101 + 102 + 103 + 104) * one
 
 
 def test_mm_least_seconds_bound():
@@ -71,9 +61,11 @@ def test_mm_least_seconds_bound():
 
 def test_projection_shapes_cover_every_projection():
     c = _config("glm4-9b-int4")
-    assert flops.projection_shapes(c) == [
-        (4096, 4096), (4096, 256), (4096, 256), (4096, 4096),
-        (4096, 13696), (4096, 13696), (13696, 4096)]
+    shapes = [(4096, 4096), (4096, 256), (4096, 256), (4096, 4096),
+              (4096, 13696), (4096, 13696), (13696, 4096)]
+    assert _work(c).projection_shapes(c) == shapes
+    # one step makes each of them once in each of the stage's 10 layers
+    assert _work(c).projection_calls(c) == [(k, n, 10) for k, n in shapes]
 
 
 def test_kernel_roofline_counts_active_rows_not_slots():
@@ -95,6 +87,6 @@ def test_kernel_roofline_counts_active_rows_not_slots():
     least, events = roof.least_seconds(ctx)
     want = 24 * sum(
         flops.mm_least_seconds(m, k, n, 8, 393e12, 819e9)[0]
-        for m in (300, 2, 1, 1) for k, n in flops.projection_shapes(c))
+        for m in (300, 2, 1, 1) for k, n in _work(c).projection_shapes(c))
     assert least == pytest.approx(want)
     assert events == 7 * 24 * 4

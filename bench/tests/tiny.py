@@ -1,5 +1,6 @@
-"""A tiny cell for CPU tests: the `lm` family at d_model 64, two layers
-and a 512-token vocabulary, served through the same harness."""
+"""A tiny cell for CPU tests: a configuration file's `lm` model at
+d_model 64, two layers and a 512-token vocabulary, with its tier, its
+stored weight bits and its control, served through the same harness."""
 import copy
 import json
 import pathlib
@@ -7,17 +8,33 @@ import pathlib
 from bench import harness
 
 BENCH = pathlib.Path(__file__).resolve().parents[1]
+# configuration files of the tests alone, served by no cell
+DATA = pathlib.Path(__file__).resolve().parent / "data"
+# the sizes of every tiny cell, which its `reduced` names
+TINY = dict(hidden_size=64, num_hidden_layers=2, num_attention_heads=4,
+            num_key_value_heads=2, head_dim=16, intermediate_size=128,
+            vocab_size=512)
 
 
-def tiny_cell(tier: str = "int8_serving", arrivals=None, limit=None,
+def config_file(name: str) -> dict:
+    """A cell's configuration file (`bench/configs`) or a test's."""
+    for folder in (BENCH / "configs", DATA):
+        path = folder / f"{name}.json"
+        if path.exists():
+            return json.loads(path.read_text())
+    raise FileNotFoundError(name)
+
+
+def tiny_config(name: str = "qwen2-0.5b-int8") -> dict:
+    c = copy.deepcopy(config_file(name))
+    c.update(name="tiny", reduced=sorted({*c["reduced"], *TINY}), **TINY)
+    return c
+
+
+def tiny_cell(config: str = "qwen2-0.5b-int8", arrivals=None, limit=None,
               **engine) -> harness.Cell:
-    base = json.loads((BENCH / "configs" / "qwen2-0.5b-int8.json").read_text())
-    c = copy.deepcopy(base)
-    c.update(name="tiny", hidden_size=64, num_hidden_layers=2,
-             num_attention_heads=4, num_key_value_heads=2, head_dim=16,
-             intermediate_size=128, vocab_size=512, precision_policy=tier,
-             control_weight_bits=4 if tier == "int8_serving" else 3)
-    c["engine"] = {**base["engine"], "batch_slots": 4, "cache_len": 96,
+    c = tiny_config(config)
+    c["engine"] = {**c["engine"], "batch_slots": 4, "cache_len": 96,
                    "prefill_chunk": 16, **engine}
     traffic = {
         "generator": "requests",
